@@ -14,7 +14,8 @@ writes the speedup to ``BENCH_api_grid.json`` at the repo root.
 The streaming benchmark drives ``reuse_distance_windows`` over a
 synthetic :class:`SyntheticChunkSource` whose trace never exists in
 memory, measuring peak RSS (each probe in its own subprocess, so
-high-water marks don't bleed between runs) and throughput, and records
+high-water marks don't bleed between runs; CPU backend only) and
+throughput, and records
 ``BENCH_streaming.json`` at the repo root for the canonical >= 10M-ref
 configuration (``--streaming-full``).
 """
@@ -156,7 +157,17 @@ print(json.dumps({
 def _rss_probe(kind: str, n: int, *, lines: int, window: int = 0,
                seed: int = 0, rate: float = 1.0) -> dict:
     """Run one scan in a fresh subprocess; return its self-reported
-    stats (ru_maxrss is a per-process high-water mark)."""
+    stats (ru_maxrss is a per-process high-water mark).
+
+    Only where no backend but the CPU is used: on a chip host the child
+    would fight this process (or the previous probe) for the device.
+    """
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "peak-RSS probes start one JAX child per probe and run only "
+            "with JAX_PLATFORMS=cpu; never start a JAX child from a "
+            "process that may hold the chip"
+        )
     env = dict(os.environ)
     env["PYTHONPATH"] = (
         str(REPO_ROOT / "src") + os.pathsep + str(REPO_ROOT)

@@ -24,7 +24,7 @@ SPEC = MatrixSpec(
 ARTIFACTS = ".cache/validate-example"
 
 print(f"matrix slice: {SPEC.describe()}\n")
-summary = run_validation(SPEC, artifact_dir=ARTIFACTS, processes=1)
+summary = run_validation(SPEC, artifact_dir=ARTIFACTS)
 
 print(f"{'architecture':<18} {'hit err %':>10} {'paper':>7} "
       f"{'runtime err %':>14} {'paper':>7}")
@@ -45,7 +45,7 @@ print(f"\nrun 1: {stats['profile_builds']} profile builds, "
       f"{stats['store_hits']} disk-store hits")
 
 # Incrementality: the store makes the second run free of profile work.
-again = run_validation(SPEC, artifact_dir=ARTIFACTS, processes=1)
+again = run_validation(SPEC, artifact_dir=ARTIFACTS)
 s2 = again["session_stats"]
 print(f"run 2: {s2['profile_builds']} profile builds, "
       f"{s2['store_hits']} disk-store hits  "

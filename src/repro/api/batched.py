@@ -31,9 +31,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.scipy.special import logsumexp
 
 from repro.core.reuse.distance import INF_RD
+from repro.kernels import interpret_mode
+from repro.kernels.f32math import exp_f32, log1p_f32, log_f32
 
 # log-space term sums stay ~1e-7-accurate in f32 up to this many ways;
 # larger set-associative geometries don't occur in Table 5 (max 20).
@@ -49,17 +50,20 @@ def _phit_row(d: jnp.ndarray, assoc: jnp.ndarray, blocks: jnp.ndarray,
     p = assoc / blocks
     p = jnp.clip(p, 1e-30, 1.0 - 1e-7)
 
+    # log/exp from repro.kernels.f32math: the TPU's own are too coarse
     d_col = df[:, None]                                   # [M, 1]
     j = jnp.arange(1, a_max, dtype=jnp.float32)           # [A-1]
-    ratios = jnp.log(jnp.maximum(d_col - j + 1.0, 1e-30)) - jnp.log(j)
+    ratios = log_f32(jnp.maximum(d_col - j + 1.0, 1e-30)) - log_f32(j)
     log_comb = jnp.concatenate(
         [jnp.zeros_like(d_col), jnp.cumsum(ratios, axis=-1)], axis=-1
     )                                                     # [M, A]
     k = jnp.arange(a_max, dtype=jnp.float32)
-    log_terms = log_comb + k * jnp.log(p) + (d_col - k) * jnp.log1p(-p)
+    log_terms = log_comb + k * log_f32(p) + (d_col - k) * log1p_f32(-p)
     valid = (k < assoc) & (k <= d_col)
-    log_terms = jnp.where(valid, log_terms, -jnp.inf)
-    s = jnp.minimum(jnp.exp(logsumexp(log_terms, axis=-1)), 1.0)
+    # binomial terms are <= 1: summed directly, without a log round trip
+    s = jnp.minimum(
+        jnp.sum(jnp.where(valid, exp_f32(log_terms), 0.0), axis=-1), 1.0
+    )
 
     out = jnp.where(df <= assoc - 1.0, 1.0, s)
     fully = jnp.where(df < blocks, 1.0, 0.0)
@@ -312,8 +316,15 @@ def _rates_body(prd_d, prd_p, crd_d, crd_p, assoc, blocks,
     for lv in range(len(a_key)):
         d, p = (prd_d, prd_p) if lv < shared_idx else (crd_d, crd_p)
         d2 = jnp.broadcast_to(d, (c, d.shape[0]))
+        # one opaque input set per level: levels that read one profile
+        # must not share their distance-only work, which would fuse
+        # (and so round: XLA:CPU contracts a*b+c into FMAs) unlike the
+        # same row in `batched_hit_rates`
+        d2, a_col, b_col = lax.optimization_barrier(
+            (d2, assoc[:, lv], blocks[:, lv])
+        )
         phit = jax.vmap(_phit_row, in_axes=(0, 0, 0, None))(
-            d2, assoc[:, lv], blocks[:, lv], a_key[lv]
+            d2, a_col, b_col, a_key[lv]
         )
         cols.append(
             jnp.sum(jnp.broadcast_to(p, d2.shape) * phit, axis=-1)
@@ -405,6 +416,13 @@ def _pad_rows(arr: np.ndarray, pad: int, value: float) -> np.ndarray:
     return np.pad(arr, width, constant_values=value)
 
 
+@jax.jit
+def _fold(probs, phit):
+    """Eq. 3 over one packed profile, summed as the vmap path sums it
+    (the profile is normalized: no division by an f32 weight sum)."""
+    return jnp.sum(probs * phit)
+
+
 def _pallas_rates(prd: DeviceProfile, crd: DeviceProfile,
                   geom: SweepGeometry, shared_idx: int,
                   interpret: bool) -> tuple[np.ndarray, int, int]:
@@ -412,10 +430,10 @@ def _pallas_rates(prd: DeviceProfile, crd: DeviceProfile,
 
     Geometry is static per Pallas compile, so configs are grouped by
     distinct (assoc, blocks) per level — one kernel call per geometry.
-    A TPU-oriented path (interpret mode off-TPU); the vmap path remains
+    A TPU-oriented path (interpret mode on the CPU); the vmap path remains
     the default.  Returns (rates, dispatches, new compiles).
     """
-    from repro.kernels.sdcm import sdcm_hit_rate
+    from repro.kernels.sdcm import sdcm_hit_probs
 
     c, n_levels = geom.assoc.shape
     rates = np.zeros((c, n_levels), dtype=np.float64)
@@ -431,11 +449,9 @@ def _pallas_rates(prd: DeviceProfile, crd: DeviceProfile,
             compiles += _record_signature(
                 ("pallas-sdcm", a, b, prof.m, interpret)
             )
-            r = float(
-                sdcm_hit_rate(
-                    prof.d, prof.p, assoc=a, blocks=b, interpret=interpret
-                )
-            )
+            phit = sdcm_hit_probs(prof.d, assoc=a, blocks=b,
+                                  interpret=interpret)
+            r = float(_fold(prof.p, phit))
             dispatches += 1
             rates[np.asarray(idxs), lv] = r
     return rates, dispatches, compiles
@@ -481,7 +497,7 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
 
     if inner == "pallas":
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
         rates, dispatches, compiles = _pallas_rates(
             prd, crd, geom, shared_idx, interpret
         )

@@ -25,8 +25,9 @@ set-associative level (P(h|D) = 1 exactly for both D = 0 and D = 1
 whenever assoc >= 2), so the two binnings agree at the hit-rate level
 even though their histograms differ; don't diff them bin-for-bin.
 
-On CPU containers the Pallas call runs in interpret mode (same kernel
-body, traced into XLA); on TPU the identical code compiles natively.
+On the CPU backend the Pallas call runs in interpret mode (same kernel
+body, traced into XLA); on TPU the identical code compiles natively
+(:func:`repro.kernels.interpret_mode`).
 """
 from __future__ import annotations
 
@@ -37,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.reuse_hist import reuse_histogram_moments
 from repro.kernels.reuse_hist.reuse_hist import NUM_BINS
 
@@ -48,6 +50,7 @@ from .distance import (
 from .profile import ReuseProfile, profile_from_pairs
 
 __all__ = [
+    "BINNED_TOLERANCE",
     "FusedReuseHistogram",
     "binned_profile_from_distances",
     "binned_profile_windows",
@@ -55,9 +58,10 @@ __all__ = [
 ]
 
 
-def _interpret_default() -> bool:
-    """Pallas interpret mode everywhere except a real TPU backend."""
-    return jax.default_backend() != "tpu"
+#: Declared accuracy of binned profiles: SDCM hit rates from them stay
+#: within this absolute distance of the exact-profile rates (gated by
+#: ``repro.validate`` and ``chip_smoke.py``).
+BINNED_TOLERANCE = 1e-3
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
@@ -75,9 +79,7 @@ class FusedReuseHistogram:
     """
 
     def __init__(self, *, interpret: bool | None = None):
-        self.interpret = (
-            _interpret_default() if interpret is None else interpret
-        )
+        self.interpret = interpret_mode() if interpret is None else interpret
         self._hist = jnp.zeros((2, NUM_BINS), jnp.float32)
 
     def update(self, d, w=None) -> "FusedReuseHistogram":

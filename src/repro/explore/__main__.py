@@ -21,6 +21,7 @@ import json
 import sys
 
 from repro.api.session import Session
+from repro.compile_cache import enable_compile_cache
 from repro.workloads import registry
 
 from .agents import AGENTS
@@ -198,4 +199,5 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -20,10 +20,13 @@ is chosen to match the (8, 128) vreg layout.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.f32math import exp_f32, log_f32
 
 LANES = 128
 BLOCK_ROWS = 8  # (8, 128) = one f32 vreg tile
@@ -34,12 +37,13 @@ def _sdcm_kernel(d_ref, out_ref, *, assoc: int, log_p: float, log_1mp: float):
     neg = d < 0.0  # INF_RD sentinel -> miss
     dd = jnp.maximum(d, 0.0)
     # k = 0 term: (1-p)^D
-    acc = jnp.exp(dd * log_1mp)
+    acc = exp_f32(dd * log_1mp)
     log_comb = jnp.zeros_like(dd)
     for k in range(1, assoc):
         kf = float(k)
-        log_comb = log_comb + jnp.log(jnp.maximum(dd - (kf - 1.0), 1e-30)) - jnp.log(kf)
-        term = jnp.exp(log_comb + kf * log_p + (dd - kf) * log_1mp)
+        log_comb = (log_comb + log_f32(jnp.maximum(dd - (kf - 1.0), 1e-30))
+                    - math.log(kf))
+        term = exp_f32(log_comb + kf * log_p + (dd - kf) * log_1mp)
         acc = acc + jnp.where(dd >= kf, term, 0.0)
     out = jnp.minimum(acc, 1.0)
     out = jnp.where(dd <= float(assoc - 1), 1.0, out)
@@ -50,8 +54,6 @@ def sdcm_pallas_2d(
     d2: jax.Array, assoc: int, blocks: int, *, interpret: bool = False
 ) -> jax.Array:
     """P(h|D) over a (rows, 128) f32 distance array (rows % 8 == 0)."""
-    import math
-
     rows, lanes = d2.shape
     assert lanes == LANES and rows % BLOCK_ROWS == 0, d2.shape
     if not 1 <= assoc <= 64:

@@ -22,6 +22,7 @@ import sys
 import threading
 
 from repro.api import AnalyticalSDCM, Session
+from repro.compile_cache import enable_compile_cache
 from repro.service.client import ServiceClient
 from repro.service.server import DEFAULT_PORT, PredictionServer, build_request
 from repro.service.service import PredictionService, ServiceConfig
@@ -167,4 +168,5 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -3,18 +3,19 @@
     python -m repro.validate                 # full matrix -> json + docs
     python -m repro.validate --smoke         # tiny matrix twice, assert
                                              # zero recomputes on run 2
-    python -m repro.validate --workloads atx jcb --processes 1
+    python -m repro.validate --workloads atx jcb
 
 The smoke mode is the CI gate: it runs the whole workload roster at
 tiny sizes twice against one shared artifact dir and fails loudly if
 the second run rebuilt any reuse profile (the disk store must make it
-fully incremental).
+fully incremental).  Everything runs in this one process.
 """
 from __future__ import annotations
 
 import argparse
 import sys
 
+from repro.compile_cache import enable_compile_cache
 from repro.validate.report import generate_report
 from repro.validate.runner import MatrixSpec, run_validation, save_results
 from repro.workloads.polybench import MAKERS
@@ -93,11 +94,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--cores", nargs="+", type=int, default=None,
                     metavar="N", help="core counts (default: 1 2 4 8)")
     ap.add_argument("--artifact-dir", default=".validation-cache",
-                    help="shared disk store (cross-run incrementality + "
-                         "the worker-shard channel; default: "
-                         ".validation-cache, gitignored).  Pass 'none' "
-                         "to disable and recompute everything serially")
-    ap.add_argument("--processes", type=int, default=None)
+                    help="shared disk store (cross-run incrementality; "
+                         "default: .validation-cache, gitignored).  Pass "
+                         "'none' to disable and recompute everything")
     ap.add_argument("--out", default=None,
                     help="results json path (default: experiments/results/"
                          "validation_full.json or validation_smoke.json)")
@@ -145,10 +144,8 @@ def main(argv: list[str] | None = None) -> int:
         if not args.artifact_dir:
             ap.error("--smoke needs --artifact-dir (the incrementality "
                      "assertion is about the shared store)")
-        first = run_validation(spec, artifact_dir=args.artifact_dir,
-                               processes=args.processes)
-        second = run_validation(spec, artifact_dir=args.artifact_dir,
-                                processes=args.processes)
+        first = run_validation(spec, artifact_dir=args.artifact_dir)
+        second = run_validation(spec, artifact_dir=args.artifact_dir)
         s2 = second["session_stats"]
         rebuilt = s2.get("profile_builds", 0) + s2.get("rd_builds", 0)
         summary = {
@@ -183,8 +180,7 @@ def main(argv: list[str] | None = None) -> int:
                 return 1
         return 0
 
-    summary = run_validation(spec, artifact_dir=args.artifact_dir,
-                             processes=args.processes)
+    summary = run_validation(spec, artifact_dir=args.artifact_dir)
     out = args.out or "experiments/results/validation_full.json"
     save_results(summary, out)
     print(f"wrote {out}")
@@ -228,4 +224,5 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

@@ -1,4 +1,4 @@
-"""Multi-process paper-matrix validation runner.
+"""Paper-matrix validation runner.
 
 Executes the paper's full validation matrix — every
 ``repro.workloads.polybench`` workload × the three Table-5 CPUs ×
@@ -13,13 +13,11 @@ the paper does:
   with exact rates, relative error in percent (isolates the SDCM
   approximation, the paper's modeling contribution).
 
-Cells are sharded across worker processes by workload (one workload's
-cells share mimicked traces, so they stay on one worker for in-memory
-cache locality); every worker layers its Session on the SAME
-disk-backed :class:`~repro.validate.store.ArtifactStore`, and results
-are merged store-mediated: each worker writes its per-workload payload
-under the ``validation`` kind and the parent reads the shards back.
-A second run with the same ``artifact_dir`` therefore performs zero
+Workloads run one after another in this process (a JAX child started
+from a parent that holds the chip would fight it for the device); each
+workload's Sessions layer on one disk-backed
+:class:`~repro.validate.store.ArtifactStore`, so a second run with the
+same ``artifact_dir`` performs zero
 reuse-profile recomputations (``session_stats.profile_builds == 0``)
 and zero exact-LRU resimulations — asserted by tests and the CI
 ``validation-smoke`` job.
@@ -29,7 +27,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
-import multiprocessing
 import os
 from pathlib import Path
 
@@ -42,6 +39,7 @@ from repro.api.stages import (
     shared_level_index,
     supported_runtime_models,
 )
+from repro.core.reuse.fused import BINNED_TOLERANCE
 from repro.hw.targets import CPU_TARGETS, resolve_target
 from repro.validate.reference import paper_claim, reference_record
 from repro.validate.store import ArtifactStore, atomic_write_bytes
@@ -82,8 +80,8 @@ class MatrixSpec:
     sampled_rate: float = 0.5
 
     def matrix_id(self) -> str:
-        """Stable id of the matrix — namespaces the result shards in
-        the store so different matrices never mix."""
+        """Stable id of the matrix, recorded in the summary so results
+        of different matrices are never confused."""
         blob = json.dumps(dataclasses.asdict(self), sort_keys=True)
         return hashlib.sha1(blob.encode()).hexdigest()[:12]
 
@@ -128,15 +126,10 @@ def _exact_hit_rates(session: Session, store: ArtifactStore | None,
     return rates
 
 
-def _shard_key(spec: MatrixSpec, name: str) -> str:
-    """Store key of one workload's shard; registry names contain
-    ``/`` which must not become directory separators."""
-    return f"{spec.matrix_id()}-{name.replace('/', '_')}"
-
-
 def run_workload(abbr: str, spec: MatrixSpec,
                  artifact_dir: str | os.PathLike | None) -> dict:
-    """Score every matrix cell of one workload (one worker's shard)."""
+    """Score every matrix cell of one workload (one shard of the
+    summary)."""
     from repro.workloads import registry
 
     store = ArtifactStore(artifact_dir) if artifact_dir else None
@@ -293,17 +286,7 @@ def run_workload(abbr: str, spec: MatrixSpec,
         "session_stats": stats,
         "store_stats": dataclasses.asdict(store.stats) if store else None,
     }
-    if store is not None:
-        # store-mediated merge: the parent reads this shard back
-        store.put_json("validation", _shard_key(spec, w.workload_name),
-                       payload)
     return payload
-
-
-def _worker(args) -> str:
-    abbr, spec, artifact_dir = args
-    run_workload(abbr, spec, artifact_dir)
-    return abbr
 
 
 def _merge(shards: list[dict], spec: MatrixSpec) -> dict:
@@ -422,9 +405,10 @@ def _merge(shards: list[dict], spec: MatrixSpec) -> dict:
                 if binned_devs else 0.0,
                 "mean_abs_dev": float(np.mean(binned_devs))
                 if binned_devs else 0.0,
-                "tolerance": 1e-3,
+                "tolerance": BINNED_TOLERANCE,
                 "within_tolerance": bool(
-                    not binned_devs or float(np.max(binned_devs)) <= 1e-3
+                    not binned_devs
+                    or float(np.max(binned_devs)) <= BINNED_TOLERANCE
                 ),
             },
             # SHARDS-sampled profiles vs exact profiles, same SDCM:
@@ -457,53 +441,17 @@ def run_validation(
     spec: MatrixSpec | None = None,
     *,
     artifact_dir: str | os.PathLike | None = None,
-    processes: int | None = None,
 ) -> dict:
-    """Run the validation matrix and return the merged summary.
+    """Run the validation matrix in this process and return the merged
+    summary.
 
-    ``processes > 1`` shards workloads across spawned worker processes
-    that share ``artifact_dir``; ``processes=1`` (or a single workload)
-    runs in-process.  Without an ``artifact_dir`` everything is
-    recomputed (no cross-run incrementality).
+    One process only: a JAX child started from a parent that holds the
+    chip would fight it for the device.  Without an ``artifact_dir``
+    everything is recomputed (no cross-run incrementality).
     """
     spec = spec or MatrixSpec()
-    if processes is None:
-        # no store -> no channel for worker shards: default to serial
-        # rather than erroring out (an explicit processes>1 still does)
-        if artifact_dir is None:
-            processes = 1
-        else:
-            processes = max(1, min(len(spec.workloads), os.cpu_count() or 1))
-
-    if processes <= 1 or len(spec.workloads) <= 1:
-        shards = [
-            run_workload(abbr, spec, artifact_dir)
-            for abbr in spec.workloads
-        ]
-    else:
-        if artifact_dir is None:
-            raise ValueError(
-                "multi-process validation needs an artifact_dir: workers "
-                "hand their shards to the parent through the store"
-            )
-        ctx = multiprocessing.get_context("spawn")
-        jobs = [(abbr, spec, artifact_dir) for abbr in spec.workloads]
-        with ctx.Pool(processes) as pool:
-            done = pool.map(_worker, jobs)
-        # store-mediated merge: read every worker's shard back from disk
-        from repro.workloads import registry
-
-        store = ArtifactStore(artifact_dir)
-        shards = []
-        for abbr in done:
-            shard = store.get_json(
-                "validation", _shard_key(spec, registry.canonical_name(abbr))
-            )
-            if shard is None:
-                raise RuntimeError(
-                    f"worker shard for {abbr!r} missing from the store"
-                )
-            shards.append(shard)
+    shards = [run_workload(abbr, spec, artifact_dir)
+              for abbr in spec.workloads]
     return _merge(shards, spec)
 
 

@@ -3,15 +3,14 @@
 The Session's in-memory caches die with the process; every sweep in a
 new interpreter recomputed every reuse profile from scratch.  The
 :class:`ArtifactStore` persists the expensive derived artifacts —
-PRD/CRD reuse profiles (npz) and exact-LRU baselines / merged
-validation results (json) — under a directory keyed by
+PRD/CRD reuse profiles (npz) and exact-LRU baselines, workload
+metadata and explore results (json) — under a directory keyed by
 
     v{STORE_VERSION}/{kind}/{content-hash-derived key}.{npz|json}
 
-so repeated sweeps are incremental *across processes and runs*: the
-validation runner's worker processes share one store, and a second run
-with the same ``artifact_dir`` performs zero reuse-profile
-recomputations (asserted by tests and the CI smoke job).
+so repeated sweeps are incremental *across processes and runs*: a
+second validation run with the same ``artifact_dir`` performs zero
+reuse-profile recomputations (asserted by tests and the CI smoke job).
 
 Durability rules:
 
@@ -61,7 +60,7 @@ import numpy as np
 # so existing stores stay readable.
 STORE_VERSION = 4
 
-_KINDS = ("profile", "exact", "validation", "workload", "explore")
+_KINDS = ("profile", "exact", "workload", "explore")
 
 
 def atomic_write(target: Path, write_fn) -> None:

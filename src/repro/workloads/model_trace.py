@@ -19,8 +19,9 @@ the trace on a profile-store miss.
 XLA's scheduling is deterministic for a fixed (jaxlib, config, shape)
 tuple — the same cell lowers to bit-identical HLO across processes —
 which is what lets a *declared* fingerprint stand in for the trace
-content hash.  ``jax.__version__`` is folded into the fingerprint so a
-toolchain upgrade invalidates cleanly, and
+content hash.  ``jax.__version__`` and the lowering platform are folded
+into the fingerprint so a toolchain upgrade or a move between CPU and
+TPU invalidates cleanly, and
 ``Session(verify_fingerprints=True)`` cross-checks the recorded
 ``trace_content_id`` whenever the trace is rebuilt.
 """
@@ -35,7 +36,8 @@ if TYPE_CHECKING:  # pragma: no cover
 # same (arch, step) — declared fingerprints hash this.  "2": op_counts
 # now carry the per-class op_class_mix (int/load/store split), and the
 # store's workload meta must not serve the old fp/loads-only counts.
-MODEL_TRACE_VERSION = "2"
+# "3": the fingerprint names the platform the HLO is compiled for.
+MODEL_TRACE_VERSION = "3"
 
 STEPS = ("prefill", "decode", "train")
 
@@ -214,7 +216,12 @@ class ModelTraceSource:
 def fingerprint_kwargs(arch_id: str, step: str, *, granule: int = GRANULE,
                        refs_cap: int = REFS_CAP,
                        loop_cap: int = LOOP_CAP) -> dict:
-    """Everything that pins the trace bytes of a model cell."""
+    """Everything that pins the trace bytes of a model cell.
+
+    ``platform`` is the backend :meth:`ModelTraceSource.lowered_hlo`
+    compiles for: XLA optimizes the same step differently per backend,
+    so a CPU-lowered and a TPU-lowered trace must never share a key.
+    """
     import jax
 
     return {
@@ -225,6 +232,7 @@ def fingerprint_kwargs(arch_id: str, step: str, *, granule: int = GRANULE,
         "loop_cap": loop_cap,
         "model_trace_version": MODEL_TRACE_VERSION,
         "jax": jax.__version__,
+        "platform": jax.default_backend(),
     }
 
 

@@ -49,7 +49,7 @@ def test_matrix_id_stable_and_spec_sensitive():
 
 
 def test_runner_scores_every_cell(tmp_path):
-    summary = run_validation(TINY, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(TINY, artifact_dir=tmp_path)
     cells = (len(TINY.workloads) * len(TINY.targets)
              * len(TINY.core_counts) * len(TINY.strategies))
     assert len(summary["records"]) == cells
@@ -71,7 +71,7 @@ def test_binned_profile_deviation_within_tolerance(tmp_path):
     """ISSUE-5 acceptance: SDCM hit rates from fused device-binned
     profiles stay within 1e-3 absolute of the exact-profile rates on
     every scored level cell, and the runner records the comparison."""
-    summary = run_validation(TINY, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(TINY, artifact_dir=tmp_path)
     bp = summary["aggregates"]["binned_profile"]
     assert bp["cells"] > 0
     assert bp["max_abs_dev"] <= bp["tolerance"] == 1e-3
@@ -84,7 +84,7 @@ def test_binned_check_can_be_disabled(tmp_path):
     spec = MatrixSpec(workloads=("atx",), core_counts=(1,),
                       strategies=("round_robin",), sizes="smoke",
                       binned_check=False)
-    summary = run_validation(spec, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(spec, artifact_dir=tmp_path)
     assert summary["aggregates"]["binned_profile"]["cells"] == 0
     assert all("binned_abs_dev" not in r for r in summary["records"])
 
@@ -93,7 +93,7 @@ def test_sampled_profile_deviation_within_bound(tmp_path):
     """ISSUE-9 acceptance: every sampled SDCM hit rate deviates from
     the exact-profile prediction by less than the error bound its own
     profile declared, and the runner records both per level cell."""
-    summary = run_validation(TINY, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(TINY, artifact_dir=tmp_path)
     sp = summary["aggregates"]["sampled_profile"]
     assert sp["cells"] > 0
     assert sp["rate"] == TINY.sampled_rate == 0.5
@@ -110,7 +110,7 @@ def test_sampled_check_can_be_disabled(tmp_path):
     spec = MatrixSpec(workloads=("atx",), core_counts=(1,),
                       strategies=("round_robin",), sizes="smoke",
                       sampled_check=False)
-    summary = run_validation(spec, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(spec, artifact_dir=tmp_path)
     sp = summary["aggregates"]["sampled_profile"]
     assert sp["cells"] == 0 and sp["rate"] is None
     assert all("sampled_abs_dev" not in r for r in summary["records"])
@@ -146,9 +146,9 @@ def test_sampling_gate_checker():
 def test_second_run_zero_profile_recomputation(tmp_path):
     """THE acceptance criterion: same artifact_dir, run twice — the
     second run rebuilds no reuse profile and resimulates no baseline."""
-    first = run_validation(TINY, artifact_dir=tmp_path, processes=1)
+    first = run_validation(TINY, artifact_dir=tmp_path)
     assert first["session_stats"]["profile_builds"] > 0
-    second = run_validation(TINY, artifact_dir=tmp_path, processes=1)
+    second = run_validation(TINY, artifact_dir=tmp_path)
     assert second["session_stats"]["profile_builds"] == 0
     assert second["session_stats"]["rd_builds"] == 0
     assert second["session_stats"]["mimic_builds"] == 0
@@ -157,20 +157,8 @@ def test_second_run_zero_profile_recomputation(tmp_path):
     assert second["aggregates"]["overall"] == first["aggregates"]["overall"]
 
 
-@pytest.mark.slow
-def test_multiprocess_workers_share_store(tmp_path):
-    """Worker-sharded cells with store-mediated merging: two spawned
-    workers produce the same summary a serial run does, and leave the
-    store warm enough that a serial rerun rebuilds nothing."""
-    summary = run_validation(TINY, artifact_dir=tmp_path, processes=2)
-    assert len(summary["records"]) == 12
-    rerun = run_validation(TINY, artifact_dir=tmp_path, processes=1)
-    assert rerun["session_stats"]["profile_builds"] == 0
-    assert rerun["aggregates"]["overall"] == summary["aggregates"]["overall"]
-
-
 def test_report_generation(tmp_path):
-    summary = run_validation(TINY, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(TINY, artifact_dir=tmp_path)
     json_path = save_results(summary, tmp_path / "validation.json")
     md_path = generate_report(json_path, tmp_path / "validation.md")
     md = md_path.read_text()
@@ -183,11 +171,6 @@ def test_report_generation(tmp_path):
     # summary json is loadable and self-contained
     payload = json.loads(json_path.read_text())
     assert payload["reference"]["per_arch"].keys() == PAPER_ARCH_CLAIMS.keys()
-
-
-def test_multiprocess_without_store_rejected(tmp_path):
-    with pytest.raises(ValueError, match="artifact_dir"):
-        run_validation(TINY, artifact_dir=None, processes=2)
 
 
 def test_no_store_defaults_to_serial():
@@ -207,7 +190,7 @@ def test_model_workload_cell_through_matrix(tmp_path):
                       targets=("tpu-v5e",), core_counts=(1,),
                       strategies=("round_robin",), sizes="smoke",
                       binned_check=False)
-    summary = run_validation(spec, artifact_dir=tmp_path, processes=1)
+    summary = run_validation(spec, artifact_dir=tmp_path)
     assert len(summary["records"]) == 1
     rec = summary["records"][0]
     assert rec["workload"] == "model/llama3_8b/decode"
@@ -216,7 +199,7 @@ def test_model_workload_cell_through_matrix(tmp_path):
     assert rec["t_pred_s"] > 0          # roofline runtime on the TPU
     assert summary["per_workload"]["model/llama3_8b/decode"]["refs"] > 0
 
-    second = run_validation(spec, artifact_dir=tmp_path, processes=1)
+    second = run_validation(spec, artifact_dir=tmp_path)
     assert second["session_stats"]["trace_builds"] == 0
     assert second["session_stats"]["profile_builds"] == 0
     assert second["aggregates"]["overall"] == summary["aggregates"]["overall"]

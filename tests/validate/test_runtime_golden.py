@@ -34,7 +34,7 @@ TOL = 1e-6
 
 @pytest.fixture(scope="module")
 def summary():
-    return run_validation(GOLDEN_SPEC, artifact_dir=None, processes=1)
+    return run_validation(GOLDEN_SPEC, artifact_dir=None)
 
 
 def test_golden_hit_and_runtime_aggregates(summary):

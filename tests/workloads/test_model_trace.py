@@ -39,6 +39,19 @@ def test_determinism_same_fingerprint_and_bitidentical_trace():
     assert ta.shared_mask.any() and not ta.shared_mask.all()
 
 
+def test_fingerprint_names_the_lowering_backend(monkeypatch):
+    """The HLO is compiled for the default backend, and XLA lowers the
+    same step differently per backend: a TPU-lowered cell must never be
+    served a CPU-lowered trace from a shared store, or the reverse."""
+    import jax
+
+    on_cpu = R.declared_fingerprint(NAME, "smoke")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    on_tpu = R.declared_fingerprint(NAME, "smoke")
+    assert on_cpu != on_tpu
+    assert R.declared_fingerprint(NAME, "validation") == on_tpu
+
+
 def test_op_counts_served_from_store_without_lowering(tmp_path):
     """A warm store answers op_counts from workload meta — the second
     source never invokes XLA."""
