@@ -20,6 +20,13 @@ in a lone single-request grid or coalesced with arbitrary other
 requests (``Session.predict_many``, the ``repro.service``
 microbatcher) — the property behind the service's "bit-identical to
 sequential ``Session.predict``" guarantee.
+
+The jitted programs are named for what they compute (``sdcm_grid``,
+``sdcm_sweep``, ``ecm_chain``, ``sdcm_fold``), so a profiler trace's
+device ops read ``jit_sdcm_sweep/...``.  Each call is wrapped in
+:mod:`repro.telemetry` spans: ``sdcm.grid`` / ``sdcm.sweep`` around
+the host staging, ``sdcm.dispatch`` around each padded transfer and
+jitted call, ``sdcm.fetch`` around the host's wait for its result.
 """
 from __future__ import annotations
 
@@ -32,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import telemetry
 from repro.core.reuse.distance import INF_RD
 from repro.kernels import interpret_mode
 from repro.kernels.f32math import exp_f32, log1p_f32, log_f32
@@ -74,13 +82,13 @@ def _phit_row(d: jnp.ndarray, assoc: jnp.ndarray, blocks: jnp.ndarray,
 @functools.lru_cache(maxsize=None)
 def _grid_fn(a_max: int):
     @jax.jit
-    def run(d, probs, assoc, blocks):
+    def sdcm_grid(d, probs, assoc, blocks):
         phit = jax.vmap(_phit_row, in_axes=(0, 0, 0, None))(
             d, assoc, blocks, a_max
         )
         return jnp.sum(probs * phit, axis=-1)
 
-    return run
+    return sdcm_grid
 
 
 def _bucket(n: int, buckets=_A_BUCKETS) -> int:
@@ -157,46 +165,49 @@ def batched_hit_rates(items) -> list[dict[str, float]]:
     """
     from repro.api.stages import shared_level_index
 
-    rows = []           # (cell index, level name, profile, assoc, blocks)
-    for ci, (target, art) in enumerate(items):
-        shared_idx = shared_level_index(target)
-        for li, lvl in enumerate(target.levels):
-            prof = art.crd if li >= shared_idx else art.prd
-            rows.append(
-                (ci, lvl.name, prof, lvl.effective_assoc, lvl.num_lines)
-            )
-    if not rows:
-        return [{} for _ in items]
+    with telemetry.span("sdcm.grid") as span:
+        rows = []       # (cell index, level name, profile, assoc, blocks)
+        for ci, (target, art) in enumerate(items):
+            shared_idx = shared_level_index(target)
+            for li, lvl in enumerate(target.levels):
+                prof = art.crd if li >= shared_idx else art.prd
+                rows.append(
+                    (ci, lvl.name, prof, lvl.effective_assoc, lvl.num_lines)
+                )
+        span.count(len(rows))
+        if not rows:
+            return [{} for _ in items]
 
-    groups: dict[tuple[int, int], list[int]] = {}
-    for ri, (_ci, _name, prof, assoc, blocks) in enumerate(rows):
-        groups.setdefault(_row_shape_key(prof, assoc, blocks), []).append(ri)
+        groups: dict[tuple[int, int], list[int]] = {}
+        for ri, (_ci, _name, prof, assoc, blocks) in enumerate(rows):
+            groups.setdefault(
+                _row_shape_key(prof, assoc, blocks), []).append(ri)
 
-    rates = np.zeros(len(rows), dtype=np.float64)
-    for (a_max, m), idxs in groups.items():
-        d, pr = pack_profiles([rows[i][2] for i in idxs], m)
-        assoc = np.array([rows[i][3] for i in idxs], dtype=np.float32)
-        blocks = np.array([rows[i][4] for i in idxs], dtype=np.float32)
-        # pad G to pow2 with inert rows (probs 0) so the number of
-        # compiled kernels stays bounded as batch sizes vary
-        g = _pow2(len(idxs))
-        if g > len(idxs):
-            pad = g - len(idxs)
-            d = np.pad(d, ((0, pad), (0, 0)))
-            pr = np.pad(pr, ((0, pad), (0, 0)))
-            assoc = np.pad(assoc, (0, pad), constant_values=1.0)
-            blocks = np.pad(blocks, (0, pad), constant_values=2.0)
-        _record_signature(("grid", a_max, g, m))
-        out = np.asarray(
-            _grid_fn(a_max)(
-                jnp.asarray(d), jnp.asarray(pr),
-                jnp.asarray(assoc), jnp.asarray(blocks),
-            )
-        )
-        rates[idxs] = out[:len(idxs)]
-    # empty-profile rows (total == 0) follow the oracle: hit rate 0
-    empty = np.array([r[2].total == 0 for r in rows])
-    rates = np.where(empty, 0.0, rates)
+        rates = np.zeros(len(rows), dtype=np.float64)
+        for (a_max, m), idxs in groups.items():
+            d, pr = pack_profiles([rows[i][2] for i in idxs], m)
+            assoc = np.array([rows[i][3] for i in idxs], dtype=np.float32)
+            blocks = np.array([rows[i][4] for i in idxs], dtype=np.float32)
+            with telemetry.span("sdcm.dispatch", n=len(idxs)):
+                # pad G to pow2 with inert rows (probs 0) so the number
+                # of compiled kernels stays bounded as batch sizes vary
+                g = _pow2(len(idxs))
+                if g > len(idxs):
+                    pad = g - len(idxs)
+                    d = np.pad(d, ((0, pad), (0, 0)))
+                    pr = np.pad(pr, ((0, pad), (0, 0)))
+                    assoc = np.pad(assoc, (0, pad), constant_values=1.0)
+                    blocks = np.pad(blocks, (0, pad), constant_values=2.0)
+                _record_signature(("grid", a_max, g, m))
+                out = _grid_fn(a_max)(
+                    jnp.asarray(d), jnp.asarray(pr),
+                    jnp.asarray(assoc), jnp.asarray(blocks),
+                )
+            with telemetry.span("sdcm.fetch", n=len(idxs)):
+                rates[idxs] = np.asarray(out)[:len(idxs)]
+        # empty-profile rows (total == 0) follow the oracle: hit rate 0
+        empty = np.array([r[2].total == 0 for r in rows])
+        rates = np.where(empty, 0.0, rates)
 
     out: list[dict[str, float]] = [{} for _ in items]
     for (ci, name, _prof, _a, _b), rate in zip(rows, rates):
@@ -364,8 +375,9 @@ def _chain_body(rates, trans_beta, delta, cores,
 def _sweep_fn(a_key: tuple, shared_idx: int, mode: str,
               with_runtime: bool):
     @jax.jit
-    def run(prd_d, prd_p, crd_d, crd_p, assoc, blocks, trans_beta,
-            delta, cores, comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s):
+    def sdcm_sweep(prd_d, prd_p, crd_d, crd_p, assoc, blocks, trans_beta,
+                   delta, cores, comp_cy, lsu_cy, mem_ops, ram_delta,
+                   cycle_s):
         rates = _rates_body(
             prd_d, prd_p, crd_d, crd_p, assoc, blocks, a_key, shared_idx
         )
@@ -378,7 +390,7 @@ def _sweep_fn(a_key: tuple, shared_idx: int, mode: str,
         )
         return rates, t
 
-    return run
+    return sdcm_sweep
 
 
 @functools.lru_cache(maxsize=None)
@@ -388,15 +400,15 @@ def _chain_fn(n_levels: int, shared_idx: int, mode: str):
     del n_levels  # part of the cache key; shapes carry it at trace time
 
     @jax.jit
-    def run(rates, trans_beta, delta, cores,
-            comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s):
+    def ecm_chain(rates, trans_beta, delta, cores,
+                  comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s):
         return _chain_body(
             rates, trans_beta, delta, cores,
             comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
             shared_idx, mode,
         )
 
-    return run
+    return ecm_chain
 
 
 def _sweep_akey(assoc_row: np.ndarray, blocks_row: np.ndarray) -> tuple:
@@ -417,7 +429,7 @@ def _pad_rows(arr: np.ndarray, pad: int, value: float) -> np.ndarray:
 
 
 @jax.jit
-def _fold(probs, phit):
+def sdcm_fold(probs, phit):
     """Eq. 3 over one packed profile, summed as the vmap path sums it
     (the profile is normalized: no division by an f32 weight sum)."""
     return jnp.sum(probs * phit)
@@ -451,7 +463,7 @@ def _pallas_rates(prd: DeviceProfile, crd: DeviceProfile,
             )
             phit = sdcm_hit_probs(prof.d, assoc=a, blocks=b,
                                   interpret=interpret)
-            r = float(_fold(prof.p, phit))
+            r = float(sdcm_fold(prof.p, phit))
             dispatches += 1
             rates[np.asarray(idxs), lv] = r
     return rates, dispatches, compiles
@@ -474,88 +486,93 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
     stays bounded and every config's hit-rate bits are independent of
     which other configs share the sweep.
     """
-    if inner not in ("vmap", "pallas"):
-        raise ValueError(f"unknown sweep inner evaluator: {inner!r}")
-    c, n_levels = geom.assoc.shape
-    with_runtime = counts is not None
-    if with_runtime and timings is None:
-        raise ValueError("sweep_grid needs timings when counts are given")
+    with telemetry.span("sdcm.sweep", n=geom.assoc.shape[0]):
+        if inner not in ("vmap", "pallas"):
+            raise ValueError(f"unknown sweep inner evaluator: {inner!r}")
+        c, n_levels = geom.assoc.shape
+        with_runtime = counts is not None
+        if with_runtime and timings is None:
+            raise ValueError("sweep_grid needs timings when counts are given")
 
-    if with_runtime:
-        from repro.core.incore import t_comp_cy, t_lsu_cy
-
-        comp_cy = float(t_comp_cy(timings, counts, mode))
-        lsu_cy = float(t_lsu_cy(timings, counts))
-        mem_ops = float(counts.mem_ops)
-    else:
-        comp_cy = lsu_cy = mem_ops = 0.0
-
-    rates = np.zeros((c, n_levels), dtype=np.float64)
-    t_pred = np.zeros(c, dtype=np.float64) if with_runtime else None
-    dispatches = 0
-    compiles = 0
-
-    if inner == "pallas":
-        if interpret is None:
-            interpret = interpret_mode()
-        rates, dispatches, compiles = _pallas_rates(
-            prd, crd, geom, shared_idx, interpret
-        )
         if with_runtime:
-            sig = ("sweep-chain", n_levels, shared_idx, mode, _pow2(c))
-            compiles += _record_signature(sig)
-            pad = _pow2(c) - c
-            t = _chain_fn(n_levels, shared_idx, mode)(
-                jnp.asarray(
-                    _pad_rows(rates.astype(np.float32), pad, 1.0)
-                ),
-                jnp.asarray(_pad_rows(geom.trans_beta, pad, 0.0)),
-                jnp.asarray(_pad_rows(geom.delta, pad, 0.0)),
-                jnp.asarray(_pad_rows(geom.cores, pad, 1.0)),
-                comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
-            )
-            t_pred[:] = np.asarray(t, dtype=np.float64)[:c]
-            dispatches += 1
-        return SweepResult(rates, t_pred, dispatches, compiles)
+            from repro.core.incore import t_comp_cy, t_lsu_cy
 
-    # group configs by their per-level bucket tuple (static per compile)
-    groups: dict[tuple, list[int]] = {}
-    for ci in range(c):
-        groups.setdefault(
-            _sweep_akey(geom.assoc[ci], geom.blocks[ci]), []
-        ).append(ci)
+            comp_cy = float(t_comp_cy(timings, counts, mode))
+            lsu_cy = float(t_lsu_cy(timings, counts))
+            mem_ops = float(counts.mem_ops)
+        else:
+            comp_cy = lsu_cy = mem_ops = 0.0
 
-    max_m = max(prd.m, crd.m)
-    chunk_cap = max(_SWEEP_MIN_CHUNK, _pow2(SWEEP_MAX_ELEMS // max_m) // 2)
-    fn_args = (prd.d, prd.p, crd.d, crd.p)
-    for a_key, idx_list in groups.items():
-        fn = _sweep_fn(a_key, shared_idx, mode, with_runtime)
-        for lo in range(0, len(idx_list), chunk_cap):
-            idxs = np.asarray(idx_list[lo:lo + chunk_cap])
-            g = _pow2(len(idxs))
-            pad = g - len(idxs)
-            sig = ("sweep", a_key, shared_idx, mode, with_runtime,
-                   g, prd.m, crd.m)
-            compiles += _record_signature(sig)
-            out = fn(
-                *fn_args,
-                jnp.asarray(_pad_rows(geom.assoc[idxs], pad, 1.0)),
-                jnp.asarray(_pad_rows(geom.blocks[idxs], pad, 2.0)),
-                jnp.asarray(_pad_rows(geom.trans_beta[idxs], pad, 0.0)),
-                jnp.asarray(_pad_rows(geom.delta[idxs], pad, 0.0)),
-                jnp.asarray(_pad_rows(geom.cores[idxs], pad, 1.0)),
-                comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
+        rates = np.zeros((c, n_levels), dtype=np.float64)
+        t_pred = np.zeros(c, dtype=np.float64) if with_runtime else None
+        dispatches = 0
+        compiles = 0
+
+        if inner == "pallas":
+            if interpret is None:
+                interpret = interpret_mode()
+            rates, dispatches, compiles = _pallas_rates(
+                prd, crd, geom, shared_idx, interpret
             )
-            dispatches += 1
             if with_runtime:
-                r, t = out
-                t_pred[idxs] = np.asarray(t, dtype=np.float64)[:len(idxs)]
-            else:
-                r = out
-            rates[idxs] = np.asarray(r, dtype=np.float64)[:len(idxs)]
+                sig = ("sweep-chain", n_levels, shared_idx, mode, _pow2(c))
+                compiles += _record_signature(sig)
+                pad = _pow2(c) - c
+                t = _chain_fn(n_levels, shared_idx, mode)(
+                    jnp.asarray(
+                        _pad_rows(rates.astype(np.float32), pad, 1.0)
+                    ),
+                    jnp.asarray(_pad_rows(geom.trans_beta, pad, 0.0)),
+                    jnp.asarray(_pad_rows(geom.delta, pad, 0.0)),
+                    jnp.asarray(_pad_rows(geom.cores, pad, 1.0)),
+                    comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
+                )
+                t_pred[:] = np.asarray(t, dtype=np.float64)[:c]
+                dispatches += 1
+            return SweepResult(rates, t_pred, dispatches, compiles)
 
-    if prd.total == 0:
-        rates[:, :shared_idx] = 0.0
-    if crd.total == 0:
-        rates[:, shared_idx:] = 0.0
-    return SweepResult(rates, t_pred, dispatches, compiles)
+        # group configs by their per-level bucket tuple (static per compile)
+        groups: dict[tuple, list[int]] = {}
+        for ci in range(c):
+            groups.setdefault(
+                _sweep_akey(geom.assoc[ci], geom.blocks[ci]), []
+            ).append(ci)
+
+        max_m = max(prd.m, crd.m)
+        chunk_cap = max(_SWEEP_MIN_CHUNK, _pow2(SWEEP_MAX_ELEMS // max_m) // 2)
+        fn_args = (prd.d, prd.p, crd.d, crd.p)
+        for a_key, idx_list in groups.items():
+            fn = _sweep_fn(a_key, shared_idx, mode, with_runtime)
+            for lo in range(0, len(idx_list), chunk_cap):
+                idxs = np.asarray(idx_list[lo:lo + chunk_cap])
+                n = len(idxs)
+                with telemetry.span("sdcm.dispatch", n=n):
+                    g = _pow2(n)
+                    pad = g - n
+                    sig = ("sweep", a_key, shared_idx, mode, with_runtime,
+                           g, prd.m, crd.m)
+                    compiles += _record_signature(sig)
+                    out = fn(
+                        *fn_args,
+                        jnp.asarray(_pad_rows(geom.assoc[idxs], pad, 1.0)),
+                        jnp.asarray(_pad_rows(geom.blocks[idxs], pad, 2.0)),
+                        jnp.asarray(
+                            _pad_rows(geom.trans_beta[idxs], pad, 0.0)),
+                        jnp.asarray(_pad_rows(geom.delta[idxs], pad, 0.0)),
+                        jnp.asarray(_pad_rows(geom.cores[idxs], pad, 1.0)),
+                        comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
+                    )
+                dispatches += 1
+                with telemetry.span("sdcm.fetch", n=n):
+                    if with_runtime:
+                        r, t = out
+                        t_pred[idxs] = np.asarray(t, dtype=np.float64)[:n]
+                    else:
+                        r = out
+                    rates[idxs] = np.asarray(r, dtype=np.float64)[:n]
+
+        if prd.total == 0:
+            rates[:, :shared_idx] = 0.0
+        if crd.total == 0:
+            rates[:, shared_idx:] = 0.0
+        return SweepResult(rates, t_pred, dispatches, compiles)

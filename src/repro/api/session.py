@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro import telemetry
 from repro.api.request import PredictionRequest
 from repro.api.results import CellPrediction, PredictionSet
 from repro.api.stages import (
@@ -49,6 +50,14 @@ from repro.api.stages import (
 from repro.core.reuse.profile import profile_from_distances
 from repro.core.trace.types import LabeledTrace
 from repro.hw.targets import resolve_target
+
+
+def _materialize(source) -> LabeledTrace:
+    """Build a source's trace: the one place a Session generates one."""
+    with telemetry.span("workload.trace") as span:
+        trace = as_trace_source(source).trace()
+        span.count(len(trace))
+    return trace
 
 
 @dataclasses.dataclass
@@ -199,7 +208,7 @@ class Session:
             tid = self._trace_ids[sid]
             return tid, self._trace_of(tid, source)
         if not self.cache_enabled:
-            trace = as_trace_source(source).trace()
+            trace = _materialize(source)
             self.stats.trace_builds += 1
             return "", trace
         fp = getattr(source, "declared_fingerprint", None)
@@ -208,7 +217,7 @@ class Session:
             self._trace_ids[sid] = tid
             self._sources[sid] = source
             return tid, self._trace_of(tid, source)
-        trace = as_trace_source(source).trace()
+        trace = _materialize(source)
         self.stats.trace_builds += 1
         tid = trace_content_id(trace)
         self._trace_ids[sid] = tid
@@ -225,7 +234,7 @@ class Session:
         """
         if self.cache_enabled and tid in self._traces:
             return self._traces[tid]
-        trace = as_trace_source(source).trace()
+        trace = _materialize(source)
         self.stats.trace_builds += 1
         if self.cache_enabled:
             self._traces[tid] = trace
@@ -353,93 +362,94 @@ class Session:
         are rematerialized through the stage caches for trace-consuming
         models (ExactLRU ground truth).
         """
-        ws = self._resolve_window(window_size)
-        builder = self._builder_for(sampled)
-        rate = getattr(builder, "sampled", None)
-        if self.cache_enabled:
-            # id only — the trace is materialized lazily, so cells
-            # served from memory/disk never build it (store hits cost
-            # zero trace builds)
-            tid = self.identify(source)
-            trace = None
-        else:
-            tid, trace = self.load(source)
-        key = (tid, line_size, cores, strategy, seed, ws, rate)
-        if self.cache_enabled and key in self._profiles:
-            self.stats.profile_hits += 1
-            art = self._profiles[key]
-            if need_traces and not art.privates:
-                art = self._materialize_traces(
-                    art, self._trace_of(tid, source)
-                )
-                self._profiles[key] = art
-            return art
-        if self.cache_enabled and self.store is not None:
-            from repro.validate.store import (
-                builder_fingerprint,
-                load_profile_artifacts,
-            )
-
-            art = load_profile_artifacts(
-                self.store, tid, line_size, cores, strategy, seed, ws,
-                builder_fingerprint(builder),
-            )
-            if art is not None:
-                self.stats.store_hits += 1
-                if need_traces:
+        with telemetry.span("session.artifacts"):
+            ws = self._resolve_window(window_size)
+            builder = self._builder_for(sampled)
+            rate = getattr(builder, "sampled", None)
+            if self.cache_enabled:
+                # id only — the trace is materialized lazily, so cells
+                # served from memory/disk never build it (store hits cost
+                # zero trace builds)
+                tid = self.identify(source)
+                trace = None
+            else:
+                tid, trace = self.load(source)
+            key = (tid, line_size, cores, strategy, seed, ws, rate)
+            if self.cache_enabled and key in self._profiles:
+                self.stats.profile_hits += 1
+                art = self._profiles[key]
+                if need_traces and not art.privates:
                     art = self._materialize_traces(
                         art, self._trace_of(tid, source)
                     )
-                self._profiles[key] = art
+                    self._profiles[key] = art
                 return art
-        if trace is None:
-            trace = self._trace_of(tid, source)
-        binned = bool(getattr(builder, "binned", False))
-        if ws:
-            art = self._streaming_artifacts(
-                tid, trace, cores, strategy, seed, line_size, ws, builder
-            )
-        elif cores == 1:
-            if rate is not None:
-                # sampled cells bypass the exact-rd cache entirely: the
-                # builder hash-filters the trace itself
-                prof = builder.profile(trace, line_size)
-            else:
-                rds = self._reuse_distances(tid, trace, line_size)
-                if hasattr(builder, "profile_of_distances"):
-                    prof = builder.profile_of_distances(rds)
-                else:
-                    prof = profile_from_distances(rds)
-            art = ProfileArtifacts(
-                trace_id=tid, cores=1, strategy=strategy, seed=seed,
-                line_size=line_size, privates=[trace], shared=trace,
-                prd=prof, crd=prof, binned=binned, sampled=rate,
-            )
-        else:
-            privs = self._private_traces(tid, trace, cores)
-            shared = self._shared_trace(tid, privs, cores, strategy, seed)
-            # PRD of the master core (cores are symmetric by construction)
-            prd = builder.profile(privs[0], line_size)
-            crd = builder.profile(shared, line_size)
-            art = ProfileArtifacts(
-                trace_id=tid, cores=cores, strategy=strategy, seed=seed,
-                line_size=line_size, privates=privs, shared=shared,
-                prd=prd, crd=crd, binned=binned, sampled=rate,
-            )
-        self.stats.profile_builds += 1
-        if self.cache_enabled:
-            self._profiles[key] = art
-            if self.store is not None:
+            if self.cache_enabled and self.store is not None:
                 from repro.validate.store import (
                     builder_fingerprint,
-                    save_profile_artifacts,
+                    load_profile_artifacts,
                 )
 
-                save_profile_artifacts(
-                    self.store, art, builder_fingerprint(builder)
+                art = load_profile_artifacts(
+                    self.store, tid, line_size, cores, strategy, seed, ws,
+                    builder_fingerprint(builder),
                 )
-                self.stats.store_puts += 1
-        return art
+                if art is not None:
+                    self.stats.store_hits += 1
+                    if need_traces:
+                        art = self._materialize_traces(
+                            art, self._trace_of(tid, source)
+                        )
+                    self._profiles[key] = art
+                    return art
+            if trace is None:
+                trace = self._trace_of(tid, source)
+            binned = bool(getattr(builder, "binned", False))
+            if ws:
+                art = self._streaming_artifacts(
+                    tid, trace, cores, strategy, seed, line_size, ws, builder
+                )
+            elif cores == 1:
+                if rate is not None:
+                    # sampled cells bypass the exact-rd cache entirely: the
+                    # builder hash-filters the trace itself
+                    prof = builder.profile(trace, line_size)
+                else:
+                    rds = self._reuse_distances(tid, trace, line_size)
+                    if hasattr(builder, "profile_of_distances"):
+                        prof = builder.profile_of_distances(rds)
+                    else:
+                        prof = profile_from_distances(rds)
+                art = ProfileArtifacts(
+                    trace_id=tid, cores=1, strategy=strategy, seed=seed,
+                    line_size=line_size, privates=[trace], shared=trace,
+                    prd=prof, crd=prof, binned=binned, sampled=rate,
+                )
+            else:
+                privs = self._private_traces(tid, trace, cores)
+                shared = self._shared_trace(tid, privs, cores, strategy, seed)
+                # PRD of the master core (cores are symmetric by construction)
+                prd = builder.profile(privs[0], line_size)
+                crd = builder.profile(shared, line_size)
+                art = ProfileArtifacts(
+                    trace_id=tid, cores=cores, strategy=strategy, seed=seed,
+                    line_size=line_size, privates=privs, shared=shared,
+                    prd=prd, crd=crd, binned=binned, sampled=rate,
+                )
+            self.stats.profile_builds += 1
+            if self.cache_enabled:
+                self._profiles[key] = art
+                if self.store is not None:
+                    from repro.validate.store import (
+                        builder_fingerprint,
+                        save_profile_artifacts,
+                    )
+
+                    save_profile_artifacts(
+                        self.store, art, builder_fingerprint(builder)
+                    )
+                    self.stats.store_puts += 1
+            return art
 
     def _materialize_traces(self, art: ProfileArtifacts,
                             trace: LabeledTrace) -> ProfileArtifacts:
@@ -529,89 +539,97 @@ class Session:
         input order, bit-identical to ``[predict(s, r) for s, r in
         items]``.
         """
-        need_traces = bool(getattr(self.cache_model, "needs_traces", False))
-        plans = []
-        flat: list[tuple[object, ProfileArtifacts]] = []
-        for source, request in items:
-            tid = self.identify(source)
-            cells = list(request.cells())
-            if not cells:
-                raise ValueError(
-                    f"request matched no grid cells: {request.describe()}"
-                )
-            arts = [
-                self.artifacts(
-                    source, cell.cores, strategy=cell.strategy,
-                    seed=request.seed,
-                    line_size=cell.target.levels[0].line_size,
-                    window_size=request.window_size,
-                    sampled=request.sampled_rate,
-                    need_traces=need_traces,
-                )
-                for cell in cells
-            ]
-            plans.append((tid, request, cells, arts))
-            flat.extend((cell.target, art) for cell, art in zip(cells, arts))
+        with telemetry.span("session.predict") as span:
+            need_traces = bool(
+                getattr(self.cache_model, "needs_traces", False))
+            plans = []
+            flat: list[tuple[object, ProfileArtifacts]] = []
+            for source, request in items:
+                tid = self.identify(source)
+                cells = list(request.cells())
+                if not cells:
+                    raise ValueError(
+                        f"request matched no grid cells: {request.describe()}"
+                    )
+                arts = [
+                    self.artifacts(
+                        source, cell.cores, strategy=cell.strategy,
+                        seed=request.seed,
+                        line_size=cell.target.levels[0].line_size,
+                        window_size=request.window_size,
+                        sampled=request.sampled_rate,
+                        need_traces=need_traces,
+                    )
+                    for cell in cells
+                ]
+                plans.append((tid, request, cells, arts))
+                flat.extend(
+                    (cell.target, art) for cell, art in zip(cells, arts))
+            span.count(len(flat))
 
-        from repro.api import batched
+            from repro.api import batched
 
-        compiled_before = batched.compile_count()
-        if hasattr(self.cache_model, "hit_rates_grid"):
-            rate_dicts = self.cache_model.hit_rates_grid(flat)
-        else:
-            rate_dicts = [
-                self.cache_model.hit_rates(t, a) for t, a in flat
-            ]
-        self.stats.kernel_compiles += (
-            batched.compile_count() - compiled_before
-        )
-
-        out: list[PredictionSet] = []
-        offset = 0
-        for tid, request, cells, arts in plans:
-            rates_slice = rate_dicts[offset:offset + len(cells)]
-            offset += len(cells)
-            out.append(
-                self._assemble(tid, request, cells, arts, rates_slice)
+            compiled_before = batched.compile_count()
+            if hasattr(self.cache_model, "hit_rates_grid"):
+                rate_dicts = self.cache_model.hit_rates_grid(flat)
+            else:
+                rate_dicts = [
+                    self.cache_model.hit_rates(t, a) for t, a in flat
+                ]
+            self.stats.kernel_compiles += (
+                batched.compile_count() - compiled_before
             )
-        return out
+
+            out: list[PredictionSet] = []
+            offset = 0
+            for tid, request, cells, arts in plans:
+                rates_slice = rate_dicts[offset:offset + len(cells)]
+                offset += len(cells)
+                out.append(
+                    self._assemble(tid, request, cells, arts, rates_slice)
+                )
+            return out
 
     def _assemble(self, tid, request, cells, arts, rate_dicts
                   ) -> PredictionSet:
         predictions = []
-        for cell, art, rates in zip(cells, arts, rate_dicts):
-            timing = {}
-            rt = None
-            if request.counts is not None:
-                # precedence: per-request named model > the Session's
-                # injected stage > the target's default
-                if request.runtime_model is not None:
-                    rt = resolve_runtime_model(
-                        request.runtime_model, cell.target
+        with telemetry.span("runtime.model", n=len(cells)):
+            for cell, art, rates in zip(cells, arts, rate_dicts):
+                timing = {}
+                rt = None
+                if request.counts is not None:
+                    # precedence: per-request named model > the Session's
+                    # injected stage > the target's default
+                    if request.runtime_model is not None:
+                        rt = resolve_runtime_model(
+                            request.runtime_model, cell.target
+                        )
+                    else:
+                        rt = self.runtime_model or default_runtime_model(
+                            cell.target
+                        )
+                    timing = rt.runtime(
+                        cell.target, rates, request.counts, cell.cores,
+                        mode=cell.mode, gap_bytes=request.gap_bytes,
                     )
-                else:
-                    rt = self.runtime_model or default_runtime_model(
-                        cell.target
+                predictions.append(
+                    CellPrediction(
+                        target=cell.target.name,
+                        cores=cell.cores,
+                        strategy=cell.strategy,
+                        mode=cell.mode,
+                        hit_rates=rates,
+                        t_pred_s=timing.get("t_pred_s"),
+                        t_mem_s=timing.get("t_mem_s"),
+                        t_cpu_s=timing.get("t_cpu_s"),
+                        runtime_model=(getattr(rt, "name", None)
+                                       if rt else None),
+                        private_profile=(art.prd if request.keep_profiles
+                                         else None),
+                        shared_profile=(art.crd if request.keep_profiles
+                                        else None),
                     )
-                timing = rt.runtime(
-                    cell.target, rates, request.counts, cell.cores,
-                    mode=cell.mode, gap_bytes=request.gap_bytes,
                 )
-            predictions.append(
-                CellPrediction(
-                    target=cell.target.name,
-                    cores=cell.cores,
-                    strategy=cell.strategy,
-                    mode=cell.mode,
-                    hit_rates=rates,
-                    t_pred_s=timing.get("t_pred_s"),
-                    t_mem_s=timing.get("t_mem_s"),
-                    t_cpu_s=timing.get("t_cpu_s"),
-                    runtime_model=getattr(rt, "name", None) if rt else None,
-                    private_profile=art.prd if request.keep_profiles else None,
-                    shared_profile=art.crd if request.keep_profiles else None,
-                )
-            )
         return PredictionSet(
             predictions,
             cache_model=getattr(self.cache_model, "name", "custom"),

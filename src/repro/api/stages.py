@@ -22,6 +22,7 @@ from typing import ClassVar, Protocol, runtime_checkable
 
 import numpy as np
 
+from repro import telemetry
 from repro.core import sdcm
 from repro.core.cachesim import simulate_hierarchy
 from repro.core.levels import CacheLevelConfig
@@ -240,10 +241,13 @@ class MimicProfileBuilder:
         )
 
     def private_traces(self, trace, cores):
-        return gen_private_traces(trace, cores)
+        with telemetry.span("reuse.mimic", n=len(trace)):
+            return gen_private_traces(trace, cores)
 
     def interleave(self, privates, strategy, seed):
-        return interleave_traces(privates, strategy, seed=seed)
+        with telemetry.span("reuse.interleave",
+                            n=sum(len(p) for p in privates)):
+            return interleave_traces(privates, strategy, seed=seed)
 
     def profile(self, trace, line_size):
         if self.window_size:
@@ -261,11 +265,14 @@ class MimicProfileBuilder:
 
     def profile_of_distances(self, rds) -> ReuseProfile:
         """Distances -> profile under the builder's histogram mode."""
-        if self.binned:
-            from repro.core.reuse.fused import binned_profile_from_distances
+        with telemetry.span("reuse.histogram", n=len(rds)):
+            if self.binned:
+                from repro.core.reuse.fused import (
+                    binned_profile_from_distances,
+                )
 
-            return binned_profile_from_distances(rds)
-        return profile_from_distances(rds)
+                return binned_profile_from_distances(rds)
+            return profile_from_distances(rds)
 
     def profile_windows(
         self, source, line_size, window_size: int | None = None

@@ -23,6 +23,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
+
 INF_RD: int = -1
 
 # Streaming-scan window default.  XLA:CPU's scan carries the Fenwick
@@ -164,16 +166,17 @@ def reuse_distances(addresses, line_size: int = 1, *,
     arr = np.asarray(addresses, dtype=np.int64)
     if arr.size == 0:
         return np.empty(0, dtype=np.int64)
-    if line_size > 1:
-        arr = arr // line_size
-    if method == "offline" or (
-        method == "auto" and arr.size >= RD_OFFLINE_THRESHOLD
-    ):
-        from .batched import reuse_distances_offline
+    if method == "auto":
+        method = "offline" if arr.size >= RD_OFFLINE_THRESHOLD else "scan"
+    with telemetry.span("reuse.distance", n=arr.size, method=method):
+        if line_size > 1:
+            arr = arr // line_size
+        if method == "offline":
+            from .batched import reuse_distances_offline
 
-        return reuse_distances_offline(arr)
-    ids = compact_ids(arr)
-    return np.asarray(_fenwick_rd_scan(jnp.asarray(ids)), dtype=np.int64)
+            return reuse_distances_offline(arr)
+        ids = compact_ids(arr)
+        return np.asarray(_fenwick_rd_scan(jnp.asarray(ids)), dtype=np.int64)
 
 
 def split_by_set(

@@ -22,6 +22,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import telemetry
 from repro.api import batched
 from repro.api.session import Session
 from repro.api.stages import shared_level_index
@@ -120,82 +121,84 @@ class FusedSweepEvaluator:
 
     def _geometry(self, configs: list[CandidateConfig],
                   line_size: int, cores: int) -> batched.SweepGeometry:
-        base, li = self.base, self.level_idx
-        c = len(configs)
-        n_levels = len(base.levels)
-        assoc = np.zeros((c, n_levels), np.float32)
-        blocks = np.zeros((c, n_levels), np.float32)
-        delta = np.zeros((c, n_levels), np.float32)
-        tbeta = np.zeros((c, n_levels), np.float32)
-        # non-swept columns depend only on the (fixed) group line size
-        for lv, lvl in enumerate(base.levels):
-            if lv == li:
-                continue
-            lines = max(lvl.size_bytes // line_size, 1)
-            assoc[:, lv] = min(lvl.assoc, lines)
-            blocks[:, lv] = lines
-            delta[:, lv] = base.level_latency_cy[lv]
-        # transfer beta of boundary i is the port INTO level i+1
-        # (RAM for the last boundary) — `core/incore.py` convention
-        for bi in range(n_levels):
-            if bi == n_levels - 1:
-                tbeta[:, bi] = base.ram_beta_cy
-            else:
-                tbeta[:, bi] = base.level_beta_cy[bi + 1]
-        for ci, cfg in enumerate(configs):
-            assoc[ci, li] = cfg.ways
-            blocks[ci, li] = cfg.sets * cfg.ways
-            delta[ci, li] = cfg.latency_cy
-            if li >= 1:
-                tbeta[ci, li - 1] = cfg.beta_cy
-        return batched.SweepGeometry(
-            assoc=assoc, blocks=blocks, trans_beta=tbeta, delta=delta,
-            cores=np.full(c, float(cores), np.float32),
-        )
+        with telemetry.span("explore.geometry", n=len(configs)):
+            base, li = self.base, self.level_idx
+            c = len(configs)
+            n_levels = len(base.levels)
+            assoc = np.zeros((c, n_levels), np.float32)
+            blocks = np.zeros((c, n_levels), np.float32)
+            delta = np.zeros((c, n_levels), np.float32)
+            tbeta = np.zeros((c, n_levels), np.float32)
+            # non-swept columns depend only on the (fixed) group line size
+            for lv, lvl in enumerate(base.levels):
+                if lv == li:
+                    continue
+                lines = max(lvl.size_bytes // line_size, 1)
+                assoc[:, lv] = min(lvl.assoc, lines)
+                blocks[:, lv] = lines
+                delta[:, lv] = base.level_latency_cy[lv]
+            # transfer beta of boundary i is the port INTO level i+1
+            # (RAM for the last boundary) — `core/incore.py` convention
+            for bi in range(n_levels):
+                if bi == n_levels - 1:
+                    tbeta[:, bi] = base.ram_beta_cy
+                else:
+                    tbeta[:, bi] = base.level_beta_cy[bi + 1]
+            for ci, cfg in enumerate(configs):
+                assoc[ci, li] = cfg.ways
+                blocks[ci, li] = cfg.sets * cfg.ways
+                delta[ci, li] = cfg.latency_cy
+                if li >= 1:
+                    tbeta[ci, li - 1] = cfg.beta_cy
+            return batched.SweepGeometry(
+                assoc=assoc, blocks=blocks, trans_beta=tbeta, delta=delta,
+                cores=np.full(c, float(cores), np.float32),
+            )
 
     # --- evaluation ----------------------------------------------------------
 
     def evaluate(self, configs: list[CandidateConfig]) -> EvalResult:
         """Score a batch; results are order-aligned with ``configs``."""
-        c = len(configs)
-        n_levels = len(self.base.levels)
-        rates = np.zeros((c, n_levels), np.float64)
-        with_runtime = self.objective == "runtime"
-        t_pred = np.zeros(c, np.float64) if with_runtime else None
+        with telemetry.span("explore.evaluate", n=len(configs)):
+            c = len(configs)
+            n_levels = len(self.base.levels)
+            rates = np.zeros((c, n_levels), np.float64)
+            with_runtime = self.objective == "runtime"
+            t_pred = np.zeros(c, np.float64) if with_runtime else None
 
-        groups: dict[tuple, list[int]] = {}
-        for ci, cfg in enumerate(configs):
-            groups.setdefault(
-                (cfg.line_size, cfg.cores, cfg.strategy), []
-            ).append(ci)
+            groups: dict[tuple, list[int]] = {}
+            for ci, cfg in enumerate(configs):
+                groups.setdefault(
+                    (cfg.line_size, cfg.cores, cfg.strategy), []
+                ).append(ci)
 
-        for (line, cores, strategy), idxs in groups.items():
-            prd, crd = self._pack(line, cores, strategy)
-            geom = self._geometry(
-                [configs[i] for i in idxs], line, cores
-            )
-            res = batched.sweep_grid(
-                prd, crd, geom,
-                shared_idx=self.shared_idx,
-                counts=self.counts if with_runtime else None,
-                timings=self.timings,
-                cycle_s=self.base.cycle_s,
-                ram_delta=self.base.ram_latency_cy,
-                mode=self.mode,
-                inner=self.inner,
-            )
-            sel = np.asarray(idxs)
-            rates[sel] = res.rates
-            if with_runtime:
-                t_pred[sel] = res.t_pred_s
-            self.stats.fused_dispatches += res.dispatches
-            self.stats.kernel_compiles += res.compiles
-            self.session.stats.kernel_compiles += res.compiles
+            for (line, cores, strategy), idxs in groups.items():
+                prd, crd = self._pack(line, cores, strategy)
+                geom = self._geometry(
+                    [configs[i] for i in idxs], line, cores
+                )
+                res = batched.sweep_grid(
+                    prd, crd, geom,
+                    shared_idx=self.shared_idx,
+                    counts=self.counts if with_runtime else None,
+                    timings=self.timings,
+                    cycle_s=self.base.cycle_s,
+                    ram_delta=self.base.ram_latency_cy,
+                    mode=self.mode,
+                    inner=self.inner,
+                )
+                sel = np.asarray(idxs)
+                rates[sel] = res.rates
+                if with_runtime:
+                    t_pred[sel] = res.t_pred_s
+                self.stats.fused_dispatches += res.dispatches
+                self.stats.kernel_compiles += res.compiles
+                self.session.stats.kernel_compiles += res.compiles
 
-        self.stats.sweeps += 1
-        self.stats.configs_scored += c
-        scores = t_pred.copy() if with_runtime else 1.0 - rates[:, -1]
-        return EvalResult(scores=scores, rates=rates, t_pred_s=t_pred)
+            self.stats.sweeps += 1
+            self.stats.configs_scored += c
+            scores = t_pred.copy() if with_runtime else 1.0 - rates[:, -1]
+            return EvalResult(scores=scores, rates=rates, t_pred_s=t_pred)
 
     def scores(self, configs: list[CandidateConfig]) -> np.ndarray:
         return self.evaluate(configs).scores
