@@ -26,7 +26,9 @@ The jitted programs are named for what they compute (``sdcm_grid``,
 device ops read ``jit_sdcm_sweep/...``.  Each call is wrapped in
 :mod:`repro.telemetry` spans: ``sdcm.grid`` / ``sdcm.sweep`` around
 the host staging, ``sdcm.dispatch`` around each padded transfer and
-jitted call, ``sdcm.fetch`` around the host's wait for its result.
+jitted call (``sdcm.put`` around a sweep dispatch's one packed
+transfer, ``n`` its bytes), ``sdcm.fetch`` around the host's wait for
+its result.
 """
 from __future__ import annotations
 
@@ -250,12 +252,13 @@ def compiled_signatures() -> frozenset:
 # target) cells; a config *sweep* flips the axes: ONE fixed packed
 # profile against C candidate hardware configs.  Geometry (assoc,
 # blocks), transfer betas, level latencies and core counts are traced
-# [C, L] / [C] device arrays, so the whole sweep — SDCM hit rates AND
-# the ECM runtime chain from `core/incore.py` — is one jitted call per
-# row shape with no per-config host round-trips.  C is padded to a
-# power of two and rows are grouped by their per-level A_MAX-bucket
-# tuple, keeping the compiled-kernel set bounded and each config's
-# numerics bit-identical to `batched_hit_rates` on the same row.
+# [C, L] / [C] columns of one packed device array, so the whole sweep
+# — SDCM hit rates AND the ECM runtime chain from `core/incore.py` —
+# is one jitted call per row shape with no per-config host
+# round-trips.  C is padded to a power of two and rows are grouped by
+# their per-level A_MAX-bucket tuple, keeping the compiled-kernel set
+# bounded and each config's numerics bit-identical to
+# `batched_hit_rates` on the same row.
 
 # cap C*M elements per dispatch (f32 phit buffer <= 32 MiB); larger
 # sweeps split into pow2-sized chunks, still one dispatch per chunk.
@@ -371,13 +374,48 @@ def _chain_body(rates, trans_beta, delta, cores,
     return jnp.maximum(core_cy, sat) * cycle_s
 
 
+# A dispatch's inputs travel to the device in ONE f32 host array
+# [G, 4L+6] (one transfer, not one per axis and scalar): L columns each
+# of assoc, blocks, trans_beta and delta, then cores, then the five
+# chain scalars broadcast down their columns.  Padded rows take the
+# inert values below (cores 1.0), as `batched_hit_rates` pads.
+_SWEEP_COLUMNS = (("assoc", 1.0), ("blocks", 2.0), ("trans_beta", 0.0),
+                  ("delta", 0.0))
+_SWEEP_SCALARS = 5   # comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s
+
+
+def _pack_sweep_inputs(geom: SweepGeometry, idxs: np.ndarray, g: int,
+                       scalars: tuple) -> np.ndarray:
+    """Stage rows ``idxs`` of ``geom`` and the chain scalars, padded to
+    ``g`` rows, in the layout `_sweep_fn` unpacks."""
+    n, n_levels = len(idxs), geom.assoc.shape[1]
+    packed = np.empty((g, 4 * n_levels + 1 + _SWEEP_SCALARS), np.float32)
+    for i, (name, pad) in enumerate(_SWEEP_COLUMNS):
+        cols = slice(i * n_levels, (i + 1) * n_levels)
+        packed[:n, cols] = getattr(geom, name)[idxs]
+        packed[n:, cols] = pad
+    packed[:n, 4 * n_levels] = geom.cores[idxs]
+    packed[n:, 4 * n_levels] = 1.0
+    packed[:, 4 * n_levels + 1:] = scalars
+    return packed
+
+
 @functools.lru_cache(maxsize=None)
 def _sweep_fn(a_key: tuple, shared_idx: int, mode: str,
               with_runtime: bool):
+    n_levels = len(a_key)
+
     @jax.jit
-    def sdcm_sweep(prd_d, prd_p, crd_d, crd_p, assoc, blocks, trans_beta,
-                   delta, cores, comp_cy, lsu_cy, mem_ops, ram_delta,
-                   cycle_s):
+    def sdcm_sweep(prd_d, prd_p, crd_d, crd_p, packed):
+        assoc, blocks, trans_beta, delta = (
+            packed[:, i * n_levels:(i + 1) * n_levels] for i in range(4)
+        )
+        cores = packed[:, 4 * n_levels]
+        # strong f32 scalars: the same f32 arithmetic as the weakly
+        # typed Python floats they stand for
+        comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s = (
+            packed[0, 4 * n_levels + 1 + i] for i in range(_SWEEP_SCALARS)
+        )
         rates = _rates_body(
             prd_d, prd_p, crd_d, crd_p, assoc, blocks, a_key, shared_idx
         )
@@ -541,6 +579,7 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
         max_m = max(prd.m, crd.m)
         chunk_cap = max(_SWEEP_MIN_CHUNK, _pow2(SWEEP_MAX_ELEMS // max_m) // 2)
         fn_args = (prd.d, prd.p, crd.d, crd.p)
+        scalars = (comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s)
         for a_key, idx_list in groups.items():
             fn = _sweep_fn(a_key, shared_idx, mode, with_runtime)
             for lo in range(0, len(idx_list), chunk_cap):
@@ -548,20 +587,13 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
                 n = len(idxs)
                 with telemetry.span("sdcm.dispatch", n=n):
                     g = _pow2(n)
-                    pad = g - n
                     sig = ("sweep", a_key, shared_idx, mode, with_runtime,
                            g, prd.m, crd.m)
                     compiles += _record_signature(sig)
-                    out = fn(
-                        *fn_args,
-                        jnp.asarray(_pad_rows(geom.assoc[idxs], pad, 1.0)),
-                        jnp.asarray(_pad_rows(geom.blocks[idxs], pad, 2.0)),
-                        jnp.asarray(
-                            _pad_rows(geom.trans_beta[idxs], pad, 0.0)),
-                        jnp.asarray(_pad_rows(geom.delta[idxs], pad, 0.0)),
-                        jnp.asarray(_pad_rows(geom.cores[idxs], pad, 1.0)),
-                        comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
-                    )
+                    packed = _pack_sweep_inputs(geom, idxs, g, scalars)
+                    with telemetry.span("sdcm.put", n=packed.nbytes):
+                        packed = jax.device_put(packed)
+                    out = fn(*fn_args, packed)
                 dispatches += 1
                 with telemetry.span("sdcm.fetch", n=n):
                     if with_runtime:

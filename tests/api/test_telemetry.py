@@ -128,10 +128,12 @@ def test_sweep_records_the_layer_spans_with_their_configs():
     assert snap["explore.geometry"]["count"] == 2          # cores 1, 2
     assert snap["sdcm.dispatch"]["count"] == ev.stats.fused_dispatches // 2
     assert snap["sdcm.fetch"]["count"] == snap["sdcm.dispatch"]["count"]
+    assert snap["sdcm.put"]["count"] == snap["sdcm.dispatch"]["count"]
     evaluate = snap["explore.evaluate"]
     assert 0 <= evaluate["self_s"] < evaluate["total_s"]
     assert set(snap) == {"explore.evaluate", "explore.geometry",
-                         "sdcm.sweep", "sdcm.dispatch", "sdcm.fetch"}
+                         "sdcm.sweep", "sdcm.dispatch", "sdcm.put",
+                         "sdcm.fetch"}
 
 
 def test_cold_build_records_the_layer_spans_with_their_refs():
@@ -216,9 +218,10 @@ def test_jitted_programs_carry_stable_names():
                                    jax.ShapeDtypeStruct((8,), f32))
     cl = jax.ShapeDtypeStruct((2, 3), f32)
     m = jax.ShapeDtypeStruct((8,), f32)
+    packed = jax.ShapeDtypeStruct((2, 4 * 3 + 6), f32)
     scalars = (1.0,) * 5
     sweep = batched._sweep_fn((8, 8, 8), 2, "throughput", True).lower(
-        m, m, m, m, cl, cl, cl, cl, row, *scalars)
+        m, m, m, m, packed)
     chain = batched._chain_fn(3, 2, "throughput").lower(
         cl, cl, cl, row, *scalars)
     for lowered, name in ((grid, "sdcm_grid"), (fold, "sdcm_fold"),

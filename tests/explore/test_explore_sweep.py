@@ -7,13 +7,18 @@ inner evaluator agrees with the vmap inner to 1e-6.
 """
 from __future__ import annotations
 
+from collections import Counter
+
 import numpy as np
 import pytest
 
-from repro.api import Session
+import jax
+
+from repro import telemetry
+from repro.api import Session, batched
 from repro.api.batched import batched_hit_rates, compile_count
 from repro.api.stages import shared_level_index
-from repro.core.incore import ECMRuntimeModel
+from repro.core.incore import ECMRuntimeModel, t_comp_cy, t_lsu_cy
 from repro.core.runtime_model import OpCounts
 from repro.core.trace.types import trace_from_blocks
 from repro.explore import FusedSweepEvaluator, SearchSpace
@@ -99,6 +104,83 @@ def test_sweep_runtime_matches_host_ecm(sweep_setup):
         )["t_pred_s"]
         # traced scalars ride as f32 0-d arrays; ~1e-7 rel agreement
         assert res.t_pred_s[ci] == pytest.approx(host, rel=1e-5)
+
+
+@pytest.mark.parametrize("n", [5, 8])
+@pytest.mark.parametrize("with_runtime", [True, False])
+@pytest.mark.parametrize("mode", ["throughput", "latency"])
+def test_packed_dispatch_bit_identical_to_unpacked_inputs(
+        sweep_setup, mode, with_runtime, n):
+    """One dispatch's inputs travel packed in one array: hit rates stay
+    bit-identical to `batched_hit_rates`, and runtimes to the ECM chain
+    fed the unpacked arrays and Python-float scalars (5 configs pad to
+    a chunk of 8 rows)."""
+    source, session, _evaluator = sweep_setup
+    ev = FusedSweepEvaluator(
+        source, SPACE, session=session, mode=mode,
+        counts=COUNTS if with_runtime else None,
+    )
+    configs = [c for c in SPACE.configs() if c.cores == 1][:n]
+    assert len(configs) == n
+    res = ev.evaluate(configs)
+    assert ev.stats.fused_dispatches == 1
+
+    items = oracle_items(session, source, ev, configs)
+    oracle = batched_hit_rates(items)
+    names = [lvl.name for lvl in ev.base.levels]
+    want = [[per_level[name] for name in names] for per_level in oracle]
+    assert res.rates.tolist() == want
+    if not with_runtime:
+        assert res.t_pred_s is None
+        return
+
+    geom = ev._geometry(configs, configs[0].line_size, 1)
+    pad = batched._pow2(n) - n
+
+    def rows(a, value):
+        width = ((0, pad),) + ((0, 0),) * (a.ndim - 1)
+        return np.pad(a, width, constant_values=value)
+
+    chain = jax.jit(batched._chain_body, static_argnames=("shared_idx",
+                                                          "mode"))
+    t = chain(
+        rows(res.rates.astype(np.float32), 1.0),
+        rows(geom.trans_beta, 0.0), rows(geom.delta, 0.0),
+        rows(geom.cores, 1.0),
+        float(t_comp_cy(ev.timings, COUNTS, mode)),
+        float(t_lsu_cy(ev.timings, COUNTS)), float(COUNTS.mem_ops),
+        ev.base.ram_latency_cy, ev.base.cycle_s,
+        shared_idx=ev.shared_idx, mode=mode,
+    )
+    assert res.t_pred_s.tolist() == np.asarray(t, np.float64)[:n].tolist()
+    model = ECMRuntimeModel()
+    for ci, ((target, _art), per_level) in enumerate(zip(items, oracle)):
+        host = model.runtime(target, per_level, COUNTS, 1,
+                             mode=mode)["t_pred_s"]
+        assert res.t_pred_s[ci] == pytest.approx(host, rel=1e-5)
+
+
+def test_one_packed_put_per_sweep_dispatch(sweep_setup):
+    _source, _session, evaluator = sweep_setup
+    configs = SPACE.configs()
+    evaluator.evaluate(configs)  # warm
+    telemetry.reset()
+    try:
+        with telemetry.enable():
+            evaluator.evaluate(configs)
+        snap = telemetry.snapshot()
+    finally:
+        telemetry.reset()
+    n_levels = len(evaluator.base.levels)
+    want = 0
+    for cores in {c.cores for c in configs}:
+        group = [c for c in configs if c.cores == cores]
+        geom = evaluator._geometry(group, group[0].line_size, cores)
+        keys = Counter(map(batched._sweep_akey, geom.assoc, geom.blocks))
+        want += sum(map(batched._pow2, keys.values())) * (
+            4 * n_levels + 6) * 4
+    assert snap["sdcm.put"]["count"] == snap["sdcm.dispatch"]["count"]
+    assert snap["sdcm.put"]["n"] == want
 
 
 def test_pallas_inner_matches_vmap_inner(sweep_setup):

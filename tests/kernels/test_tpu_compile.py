@@ -88,8 +88,7 @@ def test_config_sweep_compiles(spec):
     c, levels, m = 1024, 3, 256
     _sweep_fn((8, 8, 32), 2, "throughput", True).lower(
         spec((m,)), spec((m,)), spec((m,)), spec((m,)),
-        *(spec((c, levels)) for _ in range(4)), spec((c,)),
-        *(spec(()) for _ in range(5)),
+        spec((c, 4 * levels + 6)),
     ).compile()
 
 
