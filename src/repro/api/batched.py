@@ -27,8 +27,9 @@ device ops read ``jit_sdcm_sweep/...``.  Each call is wrapped in
 :mod:`repro.telemetry` spans: ``sdcm.grid`` / ``sdcm.sweep`` around
 the host staging, ``sdcm.dispatch`` around each padded transfer and
 jitted call (``sdcm.put`` around a sweep dispatch's one packed
-transfer, ``n`` its bytes), ``sdcm.fetch`` around the host's wait for
-its result.
+transfer, ``n`` its bytes; ``sdcm.eval`` around the jitted call, ``n``
+the padded row-distance elements it evaluates), ``sdcm.fetch`` around
+the host's wait for its result.
 """
 from __future__ import annotations
 
@@ -201,10 +202,10 @@ def batched_hit_rates(items) -> list[dict[str, float]]:
                     assoc = np.pad(assoc, (0, pad), constant_values=1.0)
                     blocks = np.pad(blocks, (0, pad), constant_values=2.0)
                 _record_signature(("grid", a_max, g, m))
-                out = _grid_fn(a_max)(
-                    jnp.asarray(d), jnp.asarray(pr),
-                    jnp.asarray(assoc), jnp.asarray(blocks),
-                )
+                args = (jnp.asarray(d), jnp.asarray(pr),
+                        jnp.asarray(assoc), jnp.asarray(blocks))
+                with telemetry.span("sdcm.eval", n=g * m):
+                    out = _grid_fn(a_max)(*args)
             with telemetry.span("sdcm.fetch", n=len(idxs)):
                 rates[idxs] = np.asarray(out)[:len(idxs)]
         # empty-profile rows (total == 0) follow the oracle: hit rate 0
@@ -261,9 +262,9 @@ def compiled_signatures() -> frozenset:
 # `batched_hit_rates` on the same row.
 
 # cap C*M elements per dispatch (f32 phit buffer <= 32 MiB); larger
-# sweeps split into pow2-sized chunks, still one dispatch per chunk.
+# sweeps split into pow2-sized chunks, still one dispatch per chunk;
+# the widest profiles take chunks as small as one row.
 SWEEP_MAX_ELEMS = 1 << 23
-_SWEEP_MIN_CHUNK = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -577,7 +578,7 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
             ).append(ci)
 
         max_m = max(prd.m, crd.m)
-        chunk_cap = max(_SWEEP_MIN_CHUNK, _pow2(SWEEP_MAX_ELEMS // max_m) // 2)
+        chunk_cap = max(1, _pow2(SWEEP_MAX_ELEMS // max_m) // 2)
         fn_args = (prd.d, prd.p, crd.d, crd.p)
         scalars = (comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s)
         for a_key, idx_list in groups.items():
@@ -593,7 +594,8 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
                     packed = _pack_sweep_inputs(geom, idxs, g, scalars)
                     with telemetry.span("sdcm.put", n=packed.nbytes):
                         packed = jax.device_put(packed)
-                    out = fn(*fn_args, packed)
+                    with telemetry.span("sdcm.eval", n=g * max_m):
+                        out = fn(*fn_args, packed)
                 dispatches += 1
                 with telemetry.span("sdcm.fetch", n=n):
                     if with_runtime:

@@ -4,6 +4,7 @@ Every trace source the pipeline can consume registers here under a
 namespaced name:
 
     polybench/<abbr>      Table-4 analytic generators (polybench.py)
+    parsec/<name>         PARSEC generators beyond Table 4 (parsec.py)
     synthetic/<kind>      tracegen-built parametric access patterns
     model/<arch>/<step>   HLO-derived model-step traces (model_trace.py)
 
@@ -170,6 +171,7 @@ def _ensure_populated() -> None:
     from repro.workloads import model_trace
 
     _register_synthetics(REGISTRY)
+    _register_parsec(REGISTRY)
     model_trace.register_model_workloads(REGISTRY)
 
 
@@ -268,3 +270,19 @@ def _register_synthetics(registry: WorkloadRegistry) -> None:
                      "validation-xxl"),
             description=f"tracegen {kind} pattern",
         ))
+
+
+# --- parsec namespace --------------------------------------------------------
+
+
+def _register_parsec(registry: WorkloadRegistry) -> None:
+    from repro.workloads import parsec
+
+    registry.register(WorkloadSpec(
+        name="parsec/canneal",
+        build=lambda sizes: parsec.make_canneal(
+            **parsec.resolved_kwargs(sizes)),
+        size_kwargs=parsec.resolved_kwargs,
+        presets=tuple(sorted(parsec.SIZE_PRESETS)),
+        description="PARSEC canneal move loop on a seeded netlist",
+    ))

@@ -131,9 +131,10 @@ def test_sweep_records_the_layer_spans_with_their_configs():
     assert snap["sdcm.put"]["count"] == snap["sdcm.dispatch"]["count"]
     evaluate = snap["explore.evaluate"]
     assert 0 <= evaluate["self_s"] < evaluate["total_s"]
+    assert snap["sdcm.eval"]["count"] == snap["sdcm.dispatch"]["count"]
     assert set(snap) == {"explore.evaluate", "explore.geometry",
                          "sdcm.sweep", "sdcm.dispatch", "sdcm.put",
-                         "sdcm.fetch"}
+                         "sdcm.eval", "sdcm.fetch"}
 
 
 def test_cold_build_records_the_layer_spans_with_their_refs():
