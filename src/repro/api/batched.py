@@ -29,7 +29,8 @@ the host staging, ``sdcm.dispatch`` around each padded transfer and
 jitted call (``sdcm.put`` around a sweep dispatch's one packed
 transfer, ``n`` its bytes; ``sdcm.eval`` around the jitted call, ``n``
 the padded row-distance elements it evaluates), ``sdcm.fetch`` around
-the host's wait for its result.
+the host's wait for results: one per group of `batched_hit_rates`, one
+per `collect_sweeps` over every sweep dispatch issued before it.
 """
 from __future__ import annotations
 
@@ -508,22 +509,39 @@ def _pallas_rates(prd: DeviceProfile, crd: DeviceProfile,
     return rates, dispatches, compiles
 
 
-def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
-               geom: SweepGeometry, *, shared_idx: int,
-               counts=None, timings=None, cycle_s: float = 1.0,
-               ram_delta: float = 0.0, mode: str = "throughput",
-               inner: str = "vmap",
-               interpret: bool | None = None) -> SweepResult:
-    """Evaluate C hardware configs against one packed profile pair.
+@dataclasses.dataclass(frozen=True)
+class PendingSweep:
+    """A sweep whose dispatches are all issued and whose results are not
+    read yet: `issue_sweep` makes one, `collect_sweeps` reads it.
 
-    Returns per-config [C, L] hit rates, plus per-config predicted
-    runtime seconds when ``counts`` (an `OpCounts`) and ``timings``
-    (an `InCoreTimings`) are given — the full SDCM + ECM chain fused
-    into one jitted dispatch per row shape.  Configs are grouped by
-    their per-level A_MAX-bucket tuple and each group's C is padded to
-    a power of two (chunked at `SWEEP_MAX_ELEMS`), so the compiled set
-    stays bounded and every config's hit-rate bits are independent of
-    which other configs share the sweep.
+    ``outs`` holds ``(idxs, out)`` per dispatch: the config rows it
+    evaluates and its device outputs, whose copies to the host are
+    already under way.  ``done`` is the result of a path that read as it
+    went (``inner="pallas"``), which has no ``outs``.
+    """
+    c: int
+    n_levels: int
+    shared_idx: int
+    with_runtime: bool
+    outs: tuple
+    compiles: int
+    empty_prd: bool
+    empty_crd: bool
+    done: SweepResult | None = None
+
+
+def issue_sweep(prd: DeviceProfile, crd: DeviceProfile,
+                geom: SweepGeometry, *, shared_idx: int,
+                counts=None, timings=None, cycle_s: float = 1.0,
+                ram_delta: float = 0.0, mode: str = "throughput",
+                inner: str = "vmap",
+                interpret: bool | None = None) -> PendingSweep:
+    """Dispatch every chunk of `sweep_grid` and read none of them.
+
+    Each output's copy to the host starts when its program ends, so
+    callers issue every sweep they need and then read them all in one
+    `collect_sweeps`; the device runs one program while the host
+    stages the next.  Arguments as for `sweep_grid`.
     """
     with telemetry.span("sdcm.sweep", n=geom.assoc.shape[0]):
         if inner not in ("vmap", "pallas"):
@@ -542,17 +560,18 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
         else:
             comp_cy = lsu_cy = mem_ops = 0.0
 
-        rates = np.zeros((c, n_levels), dtype=np.float64)
-        t_pred = np.zeros(c, dtype=np.float64) if with_runtime else None
-        dispatches = 0
-        compiles = 0
-
+        pending = functools.partial(
+            PendingSweep, c=c, n_levels=n_levels, shared_idx=shared_idx,
+            with_runtime=with_runtime,
+            empty_prd=prd.total == 0, empty_crd=crd.total == 0,
+        )
         if inner == "pallas":
             if interpret is None:
                 interpret = interpret_mode()
             rates, dispatches, compiles = _pallas_rates(
                 prd, crd, geom, shared_idx, interpret
             )
+            t_pred = None
             if with_runtime:
                 sig = ("sweep-chain", n_levels, shared_idx, mode, _pow2(c))
                 compiles += _record_signature(sig)
@@ -566,9 +585,10 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
                     jnp.asarray(_pad_rows(geom.cores, pad, 1.0)),
                     comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s,
                 )
-                t_pred[:] = np.asarray(t, dtype=np.float64)[:c]
+                t_pred = np.asarray(t, dtype=np.float64)[:c]
                 dispatches += 1
-            return SweepResult(rates, t_pred, dispatches, compiles)
+            done = SweepResult(rates, t_pred, dispatches, compiles)
+            return pending(outs=(), compiles=compiles, done=done)
 
         # group configs by their per-level bucket tuple (static per compile)
         groups: dict[tuple, list[int]] = {}
@@ -581,6 +601,8 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
         chunk_cap = max(1, _pow2(SWEEP_MAX_ELEMS // max_m) // 2)
         fn_args = (prd.d, prd.p, crd.d, crd.p)
         scalars = (comp_cy, lsu_cy, mem_ops, ram_delta, cycle_s)
+        outs = []
+        compiles = 0
         for a_key, idx_list in groups.items():
             fn = _sweep_fn(a_key, shared_idx, mode, with_runtime)
             for lo in range(0, len(idx_list), chunk_cap):
@@ -596,17 +618,66 @@ def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
                         packed = jax.device_put(packed)
                     with telemetry.span("sdcm.eval", n=g * max_m):
                         out = fn(*fn_args, packed)
-                dispatches += 1
-                with telemetry.span("sdcm.fetch", n=n):
-                    if with_runtime:
-                        r, t = out
-                        t_pred[idxs] = np.asarray(t, dtype=np.float64)[:n]
-                    else:
-                        r = out
-                    rates[idxs] = np.asarray(r, dtype=np.float64)[:n]
+                    for arr in jax.tree_util.tree_leaves(out):
+                        arr.copy_to_host_async()
+                outs.append((idxs, out))
+        return pending(outs=tuple(outs), compiles=compiles)
 
-        if prd.total == 0:
-            rates[:, :shared_idx] = 0.0
-        if crd.total == 0:
-            rates[:, shared_idx:] = 0.0
-        return SweepResult(rates, t_pred, dispatches, compiles)
+
+def collect_sweeps(pending: list[PendingSweep]) -> list[SweepResult]:
+    """Read issued sweeps, one `SweepResult` each.  Every output of every
+    sweep is read in one ``jax.device_get``, so their waits and copies
+    overlap."""
+    outs = [out for p in pending for _idxs, out in p.outs]
+    if not outs:
+        return [_scatter(p, iter(())) for p in pending]
+    rows = sum(len(idxs) for p in pending for idxs, _out in p.outs)
+    with telemetry.span("sdcm.fetch", n=rows):
+        fetched = iter(jax.device_get(outs))
+        return [_scatter(p, fetched) for p in pending]
+
+
+def _scatter(p: PendingSweep, fetched) -> SweepResult:
+    """Place one sweep's host outputs (``fetched``, in issue order) in
+    its rows."""
+    if p.done is not None:
+        return p.done
+    rates = np.zeros((p.c, p.n_levels), dtype=np.float64)
+    t_pred = np.zeros(p.c, dtype=np.float64) if p.with_runtime else None
+    for idxs, _out in p.outs:
+        n = len(idxs)
+        r = next(fetched)
+        if p.with_runtime:
+            r, t = r
+            t_pred[idxs] = np.asarray(t, dtype=np.float64)[:n]
+        rates[idxs] = np.asarray(r, dtype=np.float64)[:n]
+    if p.empty_prd:
+        rates[:, :p.shared_idx] = 0.0
+    if p.empty_crd:
+        rates[:, p.shared_idx:] = 0.0
+    return SweepResult(rates, t_pred, len(p.outs), p.compiles)
+
+
+def sweep_grid(prd: DeviceProfile, crd: DeviceProfile,
+               geom: SweepGeometry, *, shared_idx: int,
+               counts=None, timings=None, cycle_s: float = 1.0,
+               ram_delta: float = 0.0, mode: str = "throughput",
+               inner: str = "vmap",
+               interpret: bool | None = None) -> SweepResult:
+    """Evaluate C hardware configs against one packed profile pair.
+
+    Returns per-config [C, L] hit rates, plus per-config predicted
+    runtime seconds when ``counts`` (an `OpCounts`) and ``timings``
+    (an `InCoreTimings`) are given — the full SDCM + ECM chain fused
+    into one jitted dispatch per row shape.  Configs are grouped by
+    their per-level A_MAX-bucket tuple and each group's C is padded to
+    a power of two (chunked at `SWEEP_MAX_ELEMS`), so the compiled set
+    stays bounded and every config's hit-rate bits are independent of
+    which other configs share the sweep.  Every chunk is dispatched
+    before any is read (`issue_sweep`, then `collect_sweeps`).
+    """
+    return collect_sweeps([issue_sweep(
+        prd, crd, geom, shared_idx=shared_idx, counts=counts,
+        timings=timings, cycle_s=cycle_s, ram_delta=ram_delta, mode=mode,
+        inner=inner, interpret=interpret,
+    )])[0]

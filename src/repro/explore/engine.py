@@ -2,11 +2,12 @@
 
 One `FusedSweepEvaluator` owns a `Session` plus device-resident packed
 reuse profiles and scores arbitrary batches of `CandidateConfig`s
-through `repro.api.batched.sweep_grid`: candidates are grouped by the
-axes that change the *profile* (line size, cores, interleave strategy)
-and everything else — geometry, latencies, betas — rides as traced
-device arrays, so a whole agent round is a handful of jitted dispatches
-regardless of how many configs it proposes.
+through `repro.api.batched`'s fused sweep: candidates are grouped by
+the axes that change the *profile* (line size, cores, interleave
+strategy) and everything else — geometry, latencies, betas — rides as
+traced device arrays, so a whole agent round is a handful of jitted
+dispatches regardless of how many configs it proposes, all issued
+before any result is read.
 
 Scores are "smaller is better":
 
@@ -172,12 +173,13 @@ class FusedSweepEvaluator:
                     (cfg.line_size, cfg.cores, cfg.strategy), []
                 ).append(ci)
 
+            pending = []
             for (line, cores, strategy), idxs in groups.items():
                 prd, crd = self._pack(line, cores, strategy)
                 geom = self._geometry(
                     [configs[i] for i in idxs], line, cores
                 )
-                res = batched.sweep_grid(
+                pending.append(batched.issue_sweep(
                     prd, crd, geom,
                     shared_idx=self.shared_idx,
                     counts=self.counts if with_runtime else None,
@@ -186,7 +188,10 @@ class FusedSweepEvaluator:
                     ram_delta=self.base.ram_latency_cy,
                     mode=self.mode,
                     inner=self.inner,
-                )
+                ))
+            # every group's dispatches are in flight: read them at once
+            results = batched.collect_sweeps(pending)
+            for idxs, res in zip(groups.values(), results):
                 sel = np.asarray(idxs)
                 rates[sel] = res.rates
                 if with_runtime:
